@@ -164,13 +164,9 @@ def bench_fused_pallas_vs_oracle(rng, n_channels: int = 4,
         results[backend] = {n: rep.num_results for n, rep in reports.items()}
         times[backend] = timeit(
             lambda: eng.execute_all(flags, advance=False, timed=False))
-    # Predicate evaluation is integer-exact between kernel and oracle; the
-    # spatial join may flip O(1-in-millions) pairs sitting exactly on the
-    # radius boundary (the kernel's MXU form t2+u2-2t.u rounds differently
-    # than the oracle's (t-u)^2), so compare with a boundary tolerance.
-    for n, want in results["oracle"].items():
-        got = results["pallas"][n]
-        assert abs(got - want) <= max(2, want // 10_000), (n, want, got)
+    # Predicate evaluation is integer-exact between kernel and oracle, and
+    # the spatial kernel evaluates the oracle's own (t-u)^2 formula.
+    assert results["pallas"] == results["oracle"], results
     total = sum(results["oracle"].values())
     emit(f"multi_channel/exec/mixed{n_channels}/fused_oracle",
          times["oracle"], f"results={total}")

@@ -24,6 +24,7 @@ from benchmarks import (aggregation, bad_index, broker_ops, churn, common,
                         compact_join, enrich, group_size, kernel_perf,
                         max_subscriptions, multi_channel, pipeline,
                         query_plan, real_world, scaling, sharded)
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig12_13_group_size": group_size.run,
@@ -53,6 +54,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also dump emitted rows as JSON (e.g. BENCH_smoke.json)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         common.set_smoke()
     print("name,us_per_call,derived")

@@ -23,44 +23,36 @@ to dispatch-bound, where an N-shard engine on one CPU core pays N
 dispatches per tick and the ratio collapses to noise. 32k subscriptions at
 group_cap=2 (16k groups) is the smallest validated join-dominant point.
 
-Device-count mechanics: ``--xla_force_host_platform_device_count`` must be
-set before jax initializes, and ``benchmarks.run`` imports jax long before
-suites execute — so each engine runs in a child process with the flag in
-its environment, reporting one CSV line back. ``python -m
-benchmarks.sharded --child ...`` is that entry point.
+Device-count mechanics: both engines run in THIS process over
+``jax.devices()`` — the 1-shard engine on ``devices[0]``, the 4-shard engine
+on ``devices[:4]``. A runtime with fewer than 4 devices fails the suite; on
+CPU, start the process with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
+(the forced count is fixed when JAX initializes). Nothing is spawned: a child
+process cannot reach a chip that its parent already holds.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
+import time
+
+import jax
+import numpy as np
 
 from benchmarks import common
-
-
-def _child(num_shards: int, n_subs: int, ingest: int, ticks: int) -> str:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.sharded", "--child",
-         str(num_shards), str(n_subs), str(ingest), str(ticks)],
-        capture_output=True, text=True, env=env, check=False)
-    for line in out.stdout.splitlines():
-        if line.startswith("CHILD,"):
-            return line
-    raise RuntimeError(
-        f"sharded child (S={num_shards}) produced no result line:\n"
-        f"{out.stdout}\n{out.stderr}")
+from repro.core import records as R
+from repro.core.channel import tweets_about_drugs
+from repro.core.plans import ExecutionFlags
+from repro.core.sharded import ShardedBADEngine
+from repro.data.synthetic import drug_tweak, tweet_batch
 
 
 def run(rng) -> None:
+    if jax.device_count() < 4:
+        raise RuntimeError(
+            f"sharded_scaling needs 4 devices, the runtime has "
+            f"{jax.device_count()}; on CPU start the process with "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=4")
     n_subs, ingest, ticks = 32000, 128, 6    # same under smoke; see above
-    rows = {}
-    for s in (1, 4):
-        tag = _child(s, n_subs, ingest, ticks).split(",")
-        rows[s] = dict(delivered=int(tag[2]), dropped=int(tag[3]),
-                       wall=float(tag[4]), ticks=int(tag[5]),
-                       p50=float(tag[6]), p99=float(tag[7]))
+    rows = {s: _measure(s, n_subs, ingest, ticks) for s in (1, 4)}
     r1, r4 = rows[1], rows[4]
     # the ratio is only meaningful over identical content, delivered exactly
     assert r1["dropped"] == r4["dropped"] == 0, (r1, r4)
@@ -83,22 +75,8 @@ def run(rng) -> None:
                     f"dispatch-to-materialize, {s} shard(s)")
 
 
-# ---------------------------------------------------------------------------
-# child process: one engine, one measurement
-# ---------------------------------------------------------------------------
-
-
-def _child_main(num_shards: int, n_subs: int, ingest: int,
-                ticks: int) -> None:
-    import time
-
-    import numpy as np
-
-    from repro.core import records as R
-    from repro.core.channel import tweets_about_drugs
-    from repro.core.plans import ExecutionFlags
-    from repro.core.sharded import ShardedBADEngine
-    from repro.data.synthetic import drug_tweak, tweet_batch
+def _measure(num_shards: int, n_subs: int, ingest: int, ticks: int) -> dict:
+    """One engine on ``jax.devices()[:num_shards]``, one measurement."""
 
     def make_tweets(rng, n, t0):
         batch = tweet_batch(rng, n, t0)
@@ -164,14 +142,5 @@ def _child_main(num_shards: int, n_subs: int, ingest: int,
             account(dr.stats)
     wall = time.perf_counter() - t0
     p50, p99 = (float(np.percentile(lat, q)) for q in (50, 99))
-    print(f"CHILD,{num_shards},{delivered},{dropped},{wall:.4f},{ticks_run},"
-          f"{p50:.6f},{p99:.6f}")
-
-
-if __name__ == "__main__":
-    if len(sys.argv) >= 6 and sys.argv[1] == "--child":
-        _child_main(*(int(a) for a in sys.argv[2:6]))
-    else:
-        print("usage: python -m benchmarks.sharded "
-              "--child <shards> <n_subs> <ingest> <ticks>", file=sys.stderr)
-        sys.exit(2)
+    return dict(delivered=delivered, dropped=dropped, wall=wall,
+                ticks=ticks_run, p50=p50, p99=p99)

@@ -2,12 +2,13 @@
 
 Users register a location; the channel pushes nearby threatening tweets
 (fixed predicates I-III + spatial_distance < 10). Shows the BAD index and
-the MXU-friendly spatial join, and periodic execution with watermarks.
+the blocked spatial-join kernel, and periodic execution with watermarks.
 
     PYTHONPATH=src python examples/crime_alerts.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import records as R
 from repro.core.channel import tweets_about_crime
 from repro.core.engine import BADEngine
@@ -37,4 +38,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

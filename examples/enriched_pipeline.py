@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import enrich
 from repro.core.channel import most_threatening_tweets, tweets_about_drugs
 from repro.core.engine import BADEngine
@@ -90,4 +91,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

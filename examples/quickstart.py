@@ -4,6 +4,7 @@
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import records as R
 from repro.core.channel import tweets_about_drugs
 from repro.core.engine import BADEngine
@@ -55,4 +56,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,7 @@ import dataclasses
 import jax.numpy as jnp
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import train
 from repro.models.model import ModelApi
 
@@ -43,4 +44,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -557,7 +557,7 @@ class BADEngine:
         # and plan bucketing read it instead of syncing on the device scalar
         # (``int(self.dataset.size)`` would block the host on every tick)
         self.size_host = 0
-        self._conds: Optional[CompiledConditions] = None
+        self._conds: CompiledConditions = compile_conditions([])
         self.index_state = bidx.BADIndexState.create(0, index_capacity)
         self._ingest_fn = None
         # (plan-cache key, arg-shape signature) pairs already executed once:
@@ -830,7 +830,9 @@ class BADEngine:
 
     def _build_ingest(self):
         conds = self._conds
-        use_pallas = self.use_pallas
+        # no channel yet (data preloaded before the first channel): nothing
+        # to index, and a 0-channel predicate_filter cannot lower on a TPU
+        use_pallas = self.use_pallas and conds.num_channels > 0
         maint = self.maintenance
 
         def ingest_step(ds, index_state, batch):

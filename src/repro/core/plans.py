@@ -745,11 +745,11 @@ def join_spatial_stream(ds: R.ActiveDataset, stream: CandStream,
                         radius: jnp.ndarray, payload_bytes: jnp.ndarray,
                         num_brokers: int) -> StreamJoin:
     """``join_spatial_all`` over a compacted stream: each entry gathers its
-    channel's user set and evaluates the euclidean oracle formula (the MXU
-    spatial kernel's |t|^2+|u|^2-2t.u form is tied to the per-channel dense
-    layout and rounds differently at boundaries — the compact family keeps
-    the oracle formula for both backends, so compacted spatial results are
-    bitwise identical to the padded oracle path)."""
+    channel's user set and evaluates the euclidean oracle formula — the
+    same formula as the ``spatial_match`` kernel, which is tied to the
+    per-channel dense layout; the compact family keeps the jnp form for both
+    backends, so compacted spatial results are bitwise identical to the
+    padded path)."""
     ch = stream.channels
     slots = jnp.maximum(stream.rows, 0) % ds.capacity
     locs = ds.location[slots]                               # (S, 2)

@@ -24,8 +24,10 @@ already speak. The partitioning model:
                 with ``route_cross_shard=True`` every tick's delivered
                 notify sIDs are regrouped onto their owner shards by the
                 ``collectives.shuffle_notify`` all-gather collective over a
-                ("shard",) mesh (host reference fallback when the runtime
-                has fewer devices than shards).
+                ("shard",) mesh of the shards' own devices.
+  devices       shard i lives on ``jax.devices()[i]``; an engine with more
+                shards than the runtime has devices is refused, so shard
+                state never doubles up on a device without saying so.
 
 Accounting telescopes globally: each shard's DeliveryStats conserves
 delivered + spilled + dropped == produced, and the merged per-channel stats
@@ -39,7 +41,6 @@ re-partitioned under the new hash with its original sIDs.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional
@@ -192,6 +193,9 @@ class ShardedBADEngine:
         self.route_cross_shard = route_cross_shard
         self.engine_kwargs = dict(engine_kwargs)
         self._devices = jax.devices()
+        self._check_devices(num_shards)
+        self._mesh = (collectives.notify_mesh(num_shards)
+                      if route_cross_shard else None)
         self._debug = False
         self._specs: Dict[str, ChannelSpec] = {}
         self._reg: Dict[str, _ChannelRegistry] = {}
@@ -207,16 +211,20 @@ class ShardedBADEngine:
     # shard plumbing
     # ------------------------------------------------------------------
 
+    def _check_devices(self, num_shards: int) -> None:
+        if num_shards > len(self._devices):
+            raise ValueError(
+                f"{num_shards} shards need {num_shards} devices; the runtime "
+                f"has {len(self._devices)} (on CPU, set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count before starting)")
+
     def _on(self, i: int):
-        """Device context for shard i: pins the shard's engine state to its
-        own XLA device when the runtime exposes several (the forced-host-
-        device CI idiom or a real mesh); single-device runtimes share."""
-        if len(self._devices) > 1:
-            return jax.default_device(self._devices[i % len(self._devices)])
-        return contextlib.nullcontext()
+        """Device context for shard i: everything the shard's engine
+        creates or runs inside it lands on ``shard_device(i)``."""
+        return jax.default_device(self.shard_device(i))
 
     def shard_device(self, i: int):
-        return self._devices[i % len(self._devices)]
+        return self._devices[i]
 
     def _make_engine(self, i: int) -> BADEngine:
         with self._on(i):
@@ -512,7 +520,6 @@ class ShardedBADEngine:
         return merged
 
     def _route(self, merged: Dict[str, ShardedExecutionReport]) -> None:
-        mesh = collectives.notify_mesh(self.num_shards)
         for name, rep in merged.items():
             if any(r.notify is None for r in rep.per_shard):
                 continue
@@ -527,12 +534,8 @@ class ShardedBADEngine:
                 else:
                     bids = self._reg[name].brokers[sids[live]]
                 owners[live] = partition.broker_owner(bids, self.num_shards)
-            if mesh is not None:
-                rep.routed = np.asarray(
-                    collectives.shuffle_notify(mesh, sids, owners))
-            else:
-                rep.routed = collectives.shuffle_notify_ref(
-                    sids, owners, self.num_shards)
+            rep.routed = np.asarray(
+                collectives.shuffle_notify(self._mesh, sids, owners))
 
     # ------------------------------------------------------------------
     # overflow surface
@@ -579,6 +582,9 @@ class ShardedBADEngine:
         registry under the new hash with its ORIGINAL global sIDs."""
         if num_shards < 1:
             raise ValueError(f"num_shards must be >= 1, got {num_shards}")
+        self._check_devices(num_shards)
+        mesh = (collectives.notify_mesh(num_shards)
+                if self.route_cross_shard else None)
         drained: Dict[str, DrainReport] = {}
         for i, e in enumerate(self.shards):
             with self._on(i):
@@ -597,6 +603,7 @@ class ShardedBADEngine:
                              src.channels[name].last_exec_size)
                       for name in self._specs}
         self.num_shards = num_shards
+        self._mesh = mesh
         self.shards = [self._make_engine(i) for i in range(num_shards)]
         self.spill = _SpillView(self)
         for i, e in enumerate(self.shards):

@@ -20,7 +20,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 from repro.distributed.partition import Rules, sanitize_spec
 from repro.kernels.flash_decode import ref as fd_ref
 
@@ -54,9 +53,9 @@ def sp_decode_attention(rules: Rules, q: jnp.ndarray, k: jnp.ndarray,
         l = jax.lax.psum(l * c, m_axis)
         return fd_ref.normalize(acc, l, qs.dtype)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(bq, bkv, bkv, blen),
-                   out_specs=bq)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(bq, bkv, bkv, blen),
+                       out_specs=bq)
     return fn(q, k, v, kv_len)
 
 
@@ -74,14 +73,18 @@ def sp_decode_attention(rules: Rules, q: jnp.ndarray, k: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def notify_mesh(num_shards: int) -> Optional[Mesh]:
-    """A ("shard",)-axis mesh over the first ``num_shards`` devices, or None
-    when the runtime has too few devices (callers fall back to
-    ``shuffle_notify_ref``). On CPU CI the devices come from
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
+def notify_mesh(num_shards: int) -> Mesh:
+    """A ("shard",)-axis mesh over the first ``num_shards`` devices. Raises
+    when the runtime has fewer devices than shards: routing never falls
+    back to the host reference ``shuffle_notify_ref`` (which stays the
+    tests' oracle). On CPU the devices come from
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``, set before the
+    process starts."""
     devices = jax.devices()
-    if num_shards < 2 or len(devices) < num_shards:
-        return None
+    if len(devices) < num_shards:
+        raise RuntimeError(
+            f"notify shuffle over {num_shards} shards needs {num_shards} "
+            f"devices; the runtime has {len(devices)}")
     return Mesh(np.array(devices[:num_shards]), ("shard",))
 
 
@@ -124,7 +127,7 @@ def shuffle_notify(mesh: Mesh, sids: jnp.ndarray,
             jnp.where(mine, sid_all, -1), mode="drop")
         return out[:out_cap][None, :]
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axis, None), P(axis, None)),
-                   out_specs=P(axis, None))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axis, None), P(axis, None)),
+                       out_specs=P(axis, None))
     return fn(jnp.asarray(sids, jnp.int32), jnp.asarray(owners, jnp.int32))

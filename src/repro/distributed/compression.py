@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 
 
 def quantize_int8(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -60,8 +59,8 @@ def compressed_psum_tree(tree: Any, residuals: Any, mesh: Mesh, axis: str
             return out.astype(xs.dtype), new_r
 
         spec = P(*((None,) * x.ndim))
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(spec, spec), out_specs=(spec, spec))
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(spec, spec), out_specs=(spec, spec))
         return fn(x, r)
 
     out = jax.tree.map(lambda x, r: reduce_leaf(x, r), tree, residuals)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import pcast_varying, shard_map
 
 
 def pipeline_forward(mesh: Mesh, axis: str, stage_fn: Callable,
@@ -62,8 +61,8 @@ def pipeline_forward(mesh: Mesh, axis: str, stage_fn: Callable,
             return (state, outputs), ()
 
         # carriers must be device-varying from the start (shard_map vma rules)
-        state0 = pcast_varying(jnp.zeros_like(xs[0]), axis)
-        outputs0 = pcast_varying(jnp.zeros_like(xs), axis)
+        state0 = jax.lax.pcast(jnp.zeros_like(xs[0]), axis, to="varying")
+        outputs0 = jax.lax.pcast(jnp.zeros_like(xs), axis, to="varying")
         (_, outputs), _ = jax.lax.scan(tick, (state0, outputs0),
                                        jnp.arange(ticks))
         # Outputs live on the last stage; broadcast to all for the caller.
@@ -74,7 +73,7 @@ def pipeline_forward(mesh: Mesh, axis: str, stage_fn: Callable,
 
     def run(stage_params_stacked, x_microbatched):
         p_specs = jax.tree.map(lambda _: P(axis), stage_params_stacked)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda sp, xx: pipelined(
                 jax.tree.map(lambda a: a[0], sp), xx),
             mesh=mesh,

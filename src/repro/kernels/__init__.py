@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the BAD data plane (plus the LM flash kernels).
+
+Every kernel entry point takes ``interpret`` explicitly; the ops wrappers
+pass ``interpret=not on_tpu()``, so a kernel runs compiled (Mosaic) on a
+TPU and in the Pallas interpreter everywhere else, and no caller can fall
+into interpret mode on the chip by omission.
+"""
+import jax
+
+
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU."""
+    return jax.default_backend() == "tpu"

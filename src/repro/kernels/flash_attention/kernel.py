@@ -70,7 +70,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            causal: bool = True, scale: float = 1.0,
                            tq: int = DEFAULT_TQ, tk: int = DEFAULT_TK,
-                           interpret: bool = True) -> jnp.ndarray:
+                           *, interpret: bool) -> jnp.ndarray:
     b, h, s, d = q.shape
     kh = k.shape[1]
     assert h % kh == 0, (h, kh)

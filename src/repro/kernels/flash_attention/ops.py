@@ -3,15 +3,11 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.flash_attention.kernel import (DEFAULT_TK, DEFAULT_TQ,
                                                   flash_attention_kernel)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -37,5 +33,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     out = flash_attention_kernel(q, k, v, causal=causal, scale=scale,
-                                 tq=tq, tk=tk, interpret=not _on_tpu())
+                                 tq=tq, tk=tk, interpret=not on_tpu())
     return out[:, :, :s]

@@ -69,7 +69,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
 @functools.partial(jax.jit, static_argnames=("scale", "tk", "interpret"))
 def flash_decode_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         kv_len: jnp.ndarray, scale: float,
-                        tk: int = DEFAULT_TK, interpret: bool = True):
+                        tk: int = DEFAULT_TK, *, interpret: bool):
     """q (B, H, D), k/v (B, KH, S, D), kv_len (B,) int32.
 
     Returns (acc (B, H, D) f32, m (B, H) f32, l (B, H) f32) — unnormalized.

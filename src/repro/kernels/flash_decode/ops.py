@@ -3,15 +3,11 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.flash_decode import ref
 from repro.kernels.flash_decode.kernel import DEFAULT_TK, flash_decode_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def decode_attention_partial(q, k, v, kv_len, scale: Optional[float] = None,
@@ -27,7 +23,7 @@ def decode_attention_partial(q, k, v, kv_len, scale: Optional[float] = None,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     return flash_decode_kernel(q, k, v, kv_len.astype(jnp.int32), scale=scale,
-                               tk=tk, interpret=not _on_tpu())
+                               tk=tk, interpret=not on_tpu())
 
 
 def decode_attention(q, k, v, kv_len, scale: Optional[float] = None,

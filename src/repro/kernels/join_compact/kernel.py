@@ -48,7 +48,7 @@ def join_pairs_kernel(tgt: jnp.ndarray, tgt_n: jnp.ndarray,
                       members: jnp.ndarray, brokers: jnp.ndarray,
                       valid: jnp.ndarray, payload: jnp.ndarray,
                       num_brokers: int, aggregated: bool,
-                      ts: int = DEFAULT_TS, interpret: bool = True):
+                      ts: int = DEFAULT_TS, *, interpret: bool):
     """(S, maxT) gathers + (S,) scalars -> the four (S, maxT) pair grids.
 
     S must be a multiple of ts (ops.py pads). Returns

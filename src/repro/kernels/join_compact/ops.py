@@ -7,14 +7,10 @@ elsewhere). Drop-in for ``ref.join_pairs`` — the ``join_fn`` hook of
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.join_compact.kernel import DEFAULT_TS, join_pairs_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def join_pairs(tgt: jnp.ndarray, tgt_n: jnp.ndarray, members: jnp.ndarray,
@@ -36,5 +32,5 @@ def join_pairs(tgt: jnp.ndarray, tgt_n: jnp.ndarray, members: jnp.ndarray,
     pv, mem, by, bids = join_pairs_kernel(
         i32(tgt), i32(tgt_n), i32(members), i32(brokers), i32(valid),
         i32(payload), num_brokers, aggregated, ts=ts,
-        interpret=not _on_tpu())
+        interpret=not on_tpu())
     return pv[:s].astype(jnp.bool_), mem[:s], by[:s], bids[:s]

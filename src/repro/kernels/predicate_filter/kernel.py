@@ -45,7 +45,7 @@ def _kernel(fields_ref, lo_ref, hi_ref, neq_ref, out_ref):
 def predicate_filter_kernel(fields: jnp.ndarray, lo: jnp.ndarray,
                             hi: jnp.ndarray, neq: jnp.ndarray,
                             tn: int = DEFAULT_TN,
-                            interpret: bool = True) -> jnp.ndarray:
+                            *, interpret: bool) -> jnp.ndarray:
     """fields (N, F) int32, bounds (C, F) int32 -> (N, C) int8 bitmap.
 
     N must be a multiple of tn (ops.py pads).

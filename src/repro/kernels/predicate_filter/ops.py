@@ -13,12 +13,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.predicates import CompiledConditions
+from repro.kernels import on_tpu
 from repro.kernels.predicate_filter import ref
 from repro.kernels.predicate_filter.kernel import DEFAULT_TN, predicate_filter_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 _CANON_CACHE: Dict[Tuple, Tuple] = {}
@@ -43,14 +40,14 @@ def predicate_filter(fields: jnp.ndarray, conds: CompiledConditions,
     """(N, F) int32 records x conditionsList -> (N, C) bool match bitmap."""
     lo, hi, neq = canonical_arrays(conds, int(fields.shape[1]))
     return predicate_filter_padded(fields, lo, hi, neq, tn=tn,
-                                   interpret=not _on_tpu())
+                                   interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("tn", "interpret"))
 def predicate_filter_padded(fields: jnp.ndarray, lo: jnp.ndarray,
                             hi: jnp.ndarray, neq: jnp.ndarray,
                             tn: int = DEFAULT_TN,
-                            interpret: bool = True) -> jnp.ndarray:
+                            *, interpret: bool) -> jnp.ndarray:
     n = fields.shape[0]
     n_pad = -n % tn
     if n_pad:
@@ -70,7 +67,7 @@ def predicate_filter_rows(fields: jnp.ndarray, conds: CompiledConditions,
     channel axis onto a leading grid dimension, one device call total.
     """
     lo, hi, neq = canonical_arrays(conds, int(fields.shape[-1]))
-    interpret = not _on_tpu()
+    interpret = not on_tpu()
 
     def one(f, l, h, q):
         return predicate_filter_padded(f, l[None], h[None], q[None], tn=tn,

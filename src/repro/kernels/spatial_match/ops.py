@@ -9,6 +9,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import on_tpu
 from repro.kernels.spatial_match.kernel import (DEFAULT_TR, DEFAULT_TU,
                                                 spatial_match_kernel)
 
@@ -17,10 +18,6 @@ from repro.kernels.spatial_match.kernel import (DEFAULT_TR, DEFAULT_TU,
 # user sets reuse the same value for their shape-bucket padding.
 FAR = 1e30
 _FAR = FAR
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def spatial_match(tweet_locs: jnp.ndarray, user_locs: jnp.ndarray,
@@ -37,18 +34,18 @@ def spatial_match(tweet_locs: jnp.ndarray, user_locs: jnp.ndarray,
         return jax.vmap(spatial_match)(tweet_locs, user_locs, radii)
     return _padded(tweet_locs, user_locs,
                    jnp.asarray(radius, jnp.float32) ** 2,
-                   interpret=not _on_tpu())
+                   interpret=not on_tpu())
 
 
 @functools.partial(jax.jit, static_argnames=("tr", "tu", "interpret"))
 def _padded(tweet_locs, user_locs, radius2, tr: int = DEFAULT_TR,
-            tu: int = DEFAULT_TU, interpret: bool = True):
+            tu: int = DEFAULT_TU, *, interpret: bool):
     r, u = tweet_locs.shape[0], user_locs.shape[0]
     rp, up = -r % tr, -u % tu
     if rp:
         tweet_locs = jnp.pad(tweet_locs, ((0, rp), (0, 0)), constant_values=_FAR)
     if up:
         user_locs = jnp.pad(user_locs, ((0, up), (0, 0)), constant_values=-_FAR)
-    out = spatial_match_kernel(tweet_locs, user_locs, radius2, tr=tr, tu=tu,
-                               interpret=interpret)
+    out = spatial_match_kernel(tweet_locs, user_locs.T, radius2, tr=tr,
+                               tu=tu, interpret=interpret)
     return out[:r, :u].astype(jnp.bool_)

@@ -11,17 +11,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions.
-
-    ``jax.sharding.AxisType`` (and ``make_mesh``'s ``axis_types`` kwarg) only
-    exist in newer JAX releases; older ones default every axis to Auto anyway,
-    so omitting the kwarg is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto-typed."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -307,7 +307,6 @@ def test_shuffle_notify_matches_ref(multidevice):
     the shard that owns it."""
     rng = np.random.default_rng(21)
     mesh = collectives.notify_mesh(4)
-    assert mesh is not None
     for trial in range(5):
         sids = rng.integers(0, 1000, (4, 24)).astype(np.int32)
         sids[rng.random((4, 24)) < 0.4] = -1
@@ -353,6 +352,24 @@ def test_routed_delivery_preserves_sids():
                 assert (owners == o).all()
         total += len(sids)
     assert total > 0
+
+
+@pytest.mark.multidevice
+def test_more_shards_than_devices_is_refused(multidevice):
+    """A shard count the runtime has no devices for is an error: shards
+    never double up on a device, and routing never falls back to the host
+    reference (``shuffle_notify_ref`` is only the tests' oracle)."""
+    n = len(multidevice) + 1
+    with pytest.raises(ValueError, match="devices"):
+        ShardedBADEngine(num_shards=n, **MATRIX_CAPS)
+    with pytest.raises(RuntimeError, match="devices"):
+        collectives.notify_mesh(n)
+    eng = ShardedBADEngine(num_shards=2, route_cross_shard=True,
+                           **MATRIX_CAPS)
+    with pytest.raises(ValueError, match="devices"):
+        eng.reshard(n)
+    assert eng.num_shards == 2
+    assert list(eng._mesh.devices.flat) == multidevice[:2]
 
 
 # ---------------------------------------------------------------------------
