@@ -564,40 +564,51 @@ def deliver_all(result: ChannelResult, group_sids: jnp.ndarray,
     result pairs follow, and the live overflow tail re-enters the output
     ring up to its window — only what overflows PAST the ring reaches the
     spill streams (the host queue as bounded last resort). ``counts``
-    threads the engine-maintained member counts through both stages."""
+    threads the engine-maintained member counts through both stages.
+
+    The stages run under named scopes, which the device trace reads:
+    ``bad.convert`` (the pairs lane up to the wire lines), ``bad.send``
+    (the sIDs lane up to the notify buffer) and ``bad.ring`` (both lanes'
+    overflow tails into the successor ring and the spill streams)."""
     if ring is not None:
         return _deliver_with_ring(result, group_sids, payload_words,
                                   max_pairs, max_notify, spill_cap, ring,
                                   epochs, caps_pairs, caps_notify,
                                   target_brokers, num_brokers, counts)
-    pack = pack_payloads_all(result, group_sids, payload_words, max_pairs,
-                             caps_pairs, target_brokers, num_brokers, counts)
-    valid2, rows2, tgt2, cumv, produced, cap_p = _pair_layout(
-        result, caps_pairs, max_pairs)
-    P = valid2.shape[1]
+    with jax.named_scope("bad.convert"):
+        pack = pack_payloads_all(result, group_sids, payload_words,
+                                 max_pairs, caps_pairs, target_brokers,
+                                 num_brokers, counts)
+    with jax.named_scope("bad.ring"):
+        valid2, rows2, tgt2, cumv, produced, cap_p = _pair_layout(
+            result, caps_pairs, max_pairs)
+        P = valid2.shape[1]
 
-    # pairs lane: spill slot (c, i) -> in-channel pair rank cap_c + i ->
-    # source pair, by binary search + gather
-    ov_p = produced - pack.delivered                           # (C,)
-    ch_r, k_r, valid_r, total_p = _spill_slots(ov_p, cap_p, spill_cap)
-    pr = _row_search(cumv, P + 1, ch_r, k_r)
-    take = lambda arr2: jnp.where(valid_r, arr2[ch_r, pr], -1)
-    pair_spill = plans.PairStream(take(rows2), jnp.where(valid_r, ch_r, -1),
-                                  take(tgt2), valid_r, total_p)
+        # pairs lane: spill slot (c, i) -> in-channel pair rank cap_c + i ->
+        # source pair, by binary search + gather
+        ov_p = produced - pack.delivered                       # (C,)
+        ch_r, k_r, valid_r, total_p = _spill_slots(ov_p, cap_p, spill_cap)
+        pr = _row_search(cumv, P + 1, ch_r, k_r)
+        take = lambda arr2: jnp.where(valid_r, arr2[ch_r, pr], -1)
+        pair_spill = plans.PairStream(take(rows2),
+                                      jnp.where(valid_r, ch_r, -1),
+                                      take(tgt2), valid_r, total_p)
 
-    # sids lane: same scheme over the send stage's member prefix sums
-    fan, (tgt2, members, cumm, cap_n) = _fanout_parts(
-        result, group_sids, max_notify, caps_notify, counts)
-    ov_s = fan.produced - fan.delivered
-    ch_s, k_s, valid_s, total_s = _spill_slots(ov_s, cap_n, spill_cap)
-    sid_cap = 1 if group_sids.shape[-1] == 0 else group_sids.shape[-1]
-    p_s = _row_search(cumm, P * sid_cap + 1, ch_s, k_s)
-    j_s = k_s - (cumm[ch_s, p_s] - members[ch_s, p_s])
-    tgt_s = jnp.maximum(tgt2[ch_s, p_s], 0)
-    vals = jnp.where(valid_s,
-                     _member_value(group_sids, ch_s, tgt_s, j_s), -1)
-    sid_spill = plans.ValueStream(vals, jnp.where(valid_s, ch_s, -1),
-                                  valid_s, total_s)
+    with jax.named_scope("bad.send"):
+        # sids lane: same scheme over the send stage's member prefix sums
+        fan, (tgt2, members, cumm, cap_n) = _fanout_parts(
+            result, group_sids, max_notify, caps_notify, counts)
+    with jax.named_scope("bad.ring"):
+        ov_s = fan.produced - fan.delivered
+        ch_s, k_s, valid_s, total_s = _spill_slots(ov_s, cap_n, spill_cap)
+        sid_cap = 1 if group_sids.shape[-1] == 0 else group_sids.shape[-1]
+        p_s = _row_search(cumm, P * sid_cap + 1, ch_s, k_s)
+        j_s = k_s - (cumm[ch_s, p_s] - members[ch_s, p_s])
+        tgt_s = jnp.maximum(tgt2[ch_s, p_s], 0)
+        vals = jnp.where(valid_s,
+                         _member_value(group_sids, ch_s, tgt_s, j_s), -1)
+        sid_spill = plans.ValueStream(vals, jnp.where(valid_s, ch_s, -1),
+                                      valid_s, total_s)
     return FusedDelivery(pack, fan, pair_spill, sid_spill)
 
 
@@ -616,111 +627,116 @@ def _deliver_with_ring(result: ChannelResult, group_sids: jnp.ndarray,
     C = result.pair_valid.shape[0]
     W = ring.window
     epochs = jnp.asarray(epochs, jnp.int32)
-    valid2, rows2, tgt2, cumv, nfresh, cap_p = _pair_layout(
-        result, caps_pairs, max_pairs)
-    P = valid2.shape[1]
     ch = jnp.arange(C, dtype=jnp.int32)[:, None]
     identity = group_sids.shape[-1] == 0
 
     # ---- pairs lane -----------------------------------------------------
-    iw = jnp.arange(W, dtype=jnp.int32)[None, :]
-    in_ring = iw < ring.pair_count[:, None]
-    live_r = in_ring & (ring.pair_epochs == epochs[:, None])
-    cumr = jnp.cumsum(live_r.astype(jnp.int32), axis=1)        # (C, W)
-    nring = cumr[:, -1]
-    stale = ring.pair_count - nring
-    produced = ring.pair_count + nfresh
-    delivered = jnp.minimum(nring + nfresh, cap_p)
+    with jax.named_scope("bad.convert"):
+        valid2, rows2, tgt2, cumv, nfresh, cap_p = _pair_layout(
+            result, caps_pairs, max_pairs)
+        P = valid2.shape[1]
+        iw = jnp.arange(W, dtype=jnp.int32)[None, :]
+        in_ring = iw < ring.pair_count[:, None]
+        live_r = in_ring & (ring.pair_epochs == epochs[:, None])
+        cumr = jnp.cumsum(live_r.astype(jnp.int32), axis=1)    # (C, W)
+        nring = cumr[:, -1]
+        stale = ring.pair_count - nring
+        produced = ring.pair_count + nfresh
+        delivered = jnp.minimum(nring + nfresh, cap_p)
 
-    def comb_pairs(q, ok):
-        """(rows, tgts) for combined-order ranks ``q`` (C, Q): ring entries
-        first, fresh pairs after."""
-        from_ring = q < nring[:, None]
-        pr = jnp.minimum(_source_pair(cumr, q), W - 1)
-        r_rows = _gather(ring.pair_rows, pr)
-        r_tgts = _gather(ring.pair_targets, pr)
-        qf = jnp.maximum(q - nring[:, None], 0)
-        pf = jnp.minimum(_source_pair(cumv, qf), P - 1)
-        rows = jnp.where(from_ring, r_rows, _gather(rows2, pf))
-        tgts = jnp.where(from_ring, r_tgts, _gather(tgt2, pf))
-        return jnp.where(ok, rows, -1), jnp.where(ok, tgts, -1)
+        def comb_pairs(q, ok):
+            """(rows, tgts) for combined-order ranks ``q`` (C, Q): ring
+            entries first, fresh pairs after."""
+            from_ring = q < nring[:, None]
+            pr = jnp.minimum(_source_pair(cumr, q), W - 1)
+            r_rows = _gather(ring.pair_rows, pr)
+            r_tgts = _gather(ring.pair_targets, pr)
+            qf = jnp.maximum(q - nring[:, None], 0)
+            pf = jnp.minimum(_source_pair(cumv, qf), P - 1)
+            rows = jnp.where(from_ring, r_rows, _gather(rows2, pf))
+            tgts = jnp.where(from_ring, r_tgts, _gather(tgt2, pf))
+            return jnp.where(ok, rows, -1), jnp.where(ok, tgts, -1)
 
-    q = jnp.broadcast_to(jnp.arange(max_pairs, dtype=jnp.int32),
-                         (C, max_pairs))
-    ok = q < delivered[:, None]
-    rows_q, tgts_q = comb_pairs(q, ok)
-    out, per_broker = _pack_lines(
-        jnp.where(ok, rows_q, 0), jnp.where(ok, tgts_q, 0), ok, ch,
-        group_sids, counts, payload_words, target_brokers, num_brokers)
-    pack = PackedDelivery(out, delivered, produced, jnp.zeros_like(valid2),
-                          per_broker)
+        q = jnp.broadcast_to(jnp.arange(max_pairs, dtype=jnp.int32),
+                             (C, max_pairs))
+        ok = q < delivered[:, None]
+        rows_q, tgts_q = comb_pairs(q, ok)
+        out, per_broker = _pack_lines(
+            jnp.where(ok, rows_q, 0), jnp.where(ok, tgts_q, 0), ok, ch,
+            group_sids, counts, payload_words, target_brokers, num_brokers)
+        pack = PackedDelivery(out, delivered, produced,
+                              jnp.zeros_like(valid2), per_broker)
 
     # live overflow tail -> output ring window, then spill stream
-    ov_live = nring + nfresh - delivered                       # (C,)
-    i_new = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32), (C, W))
-    ok_new = i_new < jnp.minimum(ov_live, W)[:, None]
-    nrows, ntgts = comb_pairs(delivered[:, None] + i_new, ok_new)
-    ring_p_count = jnp.minimum(ov_live, W)
-    r = jnp.arange(C * spill_cap, dtype=jnp.int32)
-    ch_r, i_r = r // spill_cap, r % spill_cap
-    valid_r = (W + i_r) < ov_live[ch_r]
-    # spill ranks start at delivered + W >= W >= nring, so spill slots are
-    # always FRESH-sourced: ring entries either deliver or re-enter the
-    # ring; they never demote to the host queue
-    k_r = delivered[ch_r] + W + i_r                 # combined-order rank
-    pf_r = _row_search(cumv, P + 1, ch_r, k_r - nring[ch_r])
-    sp_rows = rows2[ch_r, pf_r]
-    sp_tgts = tgt2[ch_r, pf_r]
-    total_p = jnp.sum(jnp.maximum(ov_live - W, 0))
-    pair_spill = plans.PairStream(
-        jnp.where(valid_r, sp_rows, -1), jnp.where(valid_r, ch_r, -1),
-        jnp.where(valid_r, sp_tgts, -1), valid_r, total_p)
+    with jax.named_scope("bad.ring"):
+        ov_live = nring + nfresh - delivered                   # (C,)
+        i_new = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32), (C, W))
+        ok_new = i_new < jnp.minimum(ov_live, W)[:, None]
+        nrows, ntgts = comb_pairs(delivered[:, None] + i_new, ok_new)
+        ring_p_count = jnp.minimum(ov_live, W)
+        r = jnp.arange(C * spill_cap, dtype=jnp.int32)
+        ch_r, i_r = r // spill_cap, r % spill_cap
+        valid_r = (W + i_r) < ov_live[ch_r]
+        # spill ranks start at delivered + W >= W >= nring, so spill slots
+        # are always FRESH-sourced: ring entries either deliver or re-enter
+        # the ring; they never demote to the host queue
+        k_r = delivered[ch_r] + W + i_r             # combined-order rank
+        pf_r = _row_search(cumv, P + 1, ch_r, k_r - nring[ch_r])
+        sp_rows = rows2[ch_r, pf_r]
+        sp_tgts = tgt2[ch_r, pf_r]
+        total_p = jnp.sum(jnp.maximum(ov_live - W, 0))
+        pair_spill = plans.PairStream(
+            jnp.where(valid_r, sp_rows, -1), jnp.where(valid_r, ch_r, -1),
+            jnp.where(valid_r, sp_tgts, -1), valid_r, total_p)
 
     # ---- sids lane ------------------------------------------------------
-    fan0, (tgt2, members, cumm, cap_n) = _fanout_parts(
-        result, group_sids, max_notify, caps_notify, counts)
-    rsc = ring.sid_count
-    produced_s = rsc + fan0.produced
-    delivered_s = jnp.minimum(produced_s, cap_n)
+    with jax.named_scope("bad.send"):
+        fan0, (tgt2, members, cumm, cap_n) = _fanout_parts(
+            result, group_sids, max_notify, caps_notify, counts)
+        rsc = ring.sid_count
+        produced_s = rsc + fan0.produced
+        delivered_s = jnp.minimum(produced_s, cap_n)
 
-    def comb_sids(k, ok):
-        """sIDs for combined-order ranks ``k`` (C, Q): resident ring sids
-        (a compacted prefix: direct index) first, fresh members after."""
-        from_ring = k < rsc[:, None]
-        r_val = _gather(ring.sid_values, jnp.minimum(k, W - 1))
-        kf = jnp.maximum(k - rsc[:, None], 0)
-        f_val = _member_lookup(group_sids, tgt2, members, cumm, kf, ok)
-        return jnp.where(ok, jnp.where(from_ring, r_val, f_val), -1)
+        def comb_sids(k, ok):
+            """sIDs for combined-order ranks ``k`` (C, Q): resident ring
+            sids (a compacted prefix: direct index) first, fresh members
+            after."""
+            from_ring = k < rsc[:, None]
+            r_val = _gather(ring.sid_values, jnp.minimum(k, W - 1))
+            kf = jnp.maximum(k - rsc[:, None], 0)
+            f_val = _member_lookup(group_sids, tgt2, members, cumm, kf, ok)
+            return jnp.where(ok, jnp.where(from_ring, r_val, f_val), -1)
 
-    k = jnp.broadcast_to(jnp.arange(max_notify, dtype=jnp.int32),
-                         (C, max_notify))
-    notify = comb_sids(k, k < delivered_s[:, None])
-    fan = FanoutDelivery(notify, delivered_s, produced_s)
-    ov_s = produced_s - delivered_s
-    ok_snew = i_new < jnp.minimum(ov_s, W)[:, None]
-    nsids = comb_sids(delivered_s[:, None] + i_new, ok_snew)
-    ring_s_count = jnp.minimum(ov_s, W)
-    valid_s = (W + i_r) < ov_s[ch_r]
-    # same invariant as the pairs lane: rsc <= W, so spill slots are always
-    # fresh member lookups
-    k_s = delivered_s[ch_r] + W + i_r
-    sid_cap = 1 if identity else group_sids.shape[-1]
-    kf_s = k_s - rsc[ch_r]
-    p_s = _row_search(cumm, P * sid_cap + 1, ch_r, kf_s)
-    j_s = kf_s - (cumm[ch_r, p_s] - members[ch_r, p_s])
-    tgt_s = jnp.maximum(tgt2[ch_r, p_s], 0)
-    vals = jnp.where(valid_s,
-                     _member_value(group_sids, ch_r, tgt_s, j_s), -1)
-    total_s = jnp.sum(jnp.maximum(ov_s - W, 0))
-    sid_spill = plans.ValueStream(vals, jnp.where(valid_s, ch_r, -1),
-                                  valid_s, total_s)
+        k = jnp.broadcast_to(jnp.arange(max_notify, dtype=jnp.int32),
+                             (C, max_notify))
+        notify = comb_sids(k, k < delivered_s[:, None])
+        fan = FanoutDelivery(notify, delivered_s, produced_s)
+    with jax.named_scope("bad.ring"):
+        ov_s = produced_s - delivered_s
+        ok_snew = i_new < jnp.minimum(ov_s, W)[:, None]
+        nsids = comb_sids(delivered_s[:, None] + i_new, ok_snew)
+        ring_s_count = jnp.minimum(ov_s, W)
+        valid_s = (W + i_r) < ov_s[ch_r]
+        # same invariant as the pairs lane: rsc <= W, so spill slots are
+        # always fresh member lookups
+        k_s = delivered_s[ch_r] + W + i_r
+        sid_cap = 1 if identity else group_sids.shape[-1]
+        kf_s = k_s - rsc[ch_r]
+        p_s = _row_search(cumm, P * sid_cap + 1, ch_r, kf_s)
+        j_s = kf_s - (cumm[ch_r, p_s] - members[ch_r, p_s])
+        tgt_s = jnp.maximum(tgt2[ch_r, p_s], 0)
+        vals = jnp.where(valid_s,
+                         _member_value(group_sids, ch_r, tgt_s, j_s), -1)
+        total_s = jnp.sum(jnp.maximum(ov_s - W, 0))
+        sid_spill = plans.ValueStream(vals, jnp.where(valid_s, ch_r, -1),
+                                      valid_s, total_s)
 
-    new_ring = RetryRing(
-        nrows, ntgts,
-        jnp.broadcast_to(epochs[:, None], (C, W)).astype(jnp.int32),
-        ring_p_count, nsids, ring_s_count)
-    counters = RingCounters(ring.pair_count, stale, ring_p_count,
-                            rsc, ring_s_count)
+        new_ring = RetryRing(
+            nrows, ntgts,
+            jnp.broadcast_to(epochs[:, None], (C, W)).astype(jnp.int32),
+            ring_p_count, nsids, ring_s_count)
+        counters = RingCounters(ring.pair_count, stale, ring_p_count,
+                                rsc, ring_s_count)
     return FusedDelivery(pack, fan, pair_spill, sid_spill, new_ring,
                          counters)
 

@@ -42,6 +42,7 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import bad_index as bidx
 from repro.core import enrich
@@ -861,20 +862,21 @@ class BADEngine:
         mirrors device size exactly), so ingest never blocks on the device
         queue — the returned ids are valid while the append is still in
         flight."""
-        if self._ingest_fn is None:
-            self._ingest_fn = self._build_ingest()
-        n = batch.num_records
-        row_ids = np.arange(self.size_host, self.size_host + n,
-                            dtype=np.int32)
-        self.dataset, self.index_state, _ = self._ingest_fn(
-            self.dataset, self.index_state, batch)
-        self.size_host += n
-        if n:
-            # reads the batch INPUT buffer (already materialized), not a
-            # computation output — no dispatch-queue sync
-            ts = np.asarray(batch.fields)[:, R.TIMESTAMP]
-            self.now = max(self.now, int(ts.max()))
-        return row_ids
+        with TraceAnnotation("bad.ingest"):
+            if self._ingest_fn is None:
+                self._ingest_fn = self._build_ingest()
+            n = batch.num_records
+            row_ids = np.arange(self.size_host, self.size_host + n,
+                                dtype=np.int32)
+            self.dataset, self.index_state, _ = self._ingest_fn(
+                self.dataset, self.index_state, batch)
+            self.size_host += n
+            if n:
+                # reads the batch INPUT buffer (already materialized), not a
+                # computation output — no dispatch-queue sync
+                ts = np.asarray(batch.fields)[:, R.TIMESTAMP]
+                self.now = max(self.now, int(ts.max()))
+            return row_ids
 
     # ------------------------------------------------------------------
     # data plane: channel execution
@@ -1820,7 +1822,12 @@ class BADEngine:
         state the ring buffers update in place (the dispatcher stores the
         OUTPUT ring and never re-presents the input handle; the compact
         grow loop must NOT donate — it re-presents the same ring to the
-        re-run). Returns ``(fn, key)``."""
+        re-run). Returns ``(fn, key)``.
+
+        The stages run under named scopes, which only tag the ops'
+        metadata for the device trace: ``bad.discover`` (discovery and the
+        CSR compaction), ``bad.join`` (the joins), and inside
+        ``deliver_all`` ``bad.convert``, ``bad.send`` and ``bad.ring``."""
         key = ("all", plan, max_cand, deliver, p_stream, s_stream,
                donate_rings,
                tuple((st.spec, st.index) for st in param_chs),
@@ -1898,25 +1905,28 @@ class BADEngine:
             rank_p = rank_s = None
             tot_p = tot_s = jnp.zeros((), jnp.int32)
             if p_static is not None:
-                cand = discover(ds, index_state, p_static,
-                                p_in["last_ts"], p_in["last_size"])
-                if compact:
-                    stream = plans.compact_candidates(cand, p_stream)
-                    tot_p = stream.total
-                    sj = plans.join_param_stream(
-                        ds, stream, p_in["targets"], p_in["param_field"],
-                        p_in["payload"], num_brokers,
-                        p_in["up_masks"] if pushdown else None, aggregated,
-                        p_in["domains"], join_fn)
-                    res_p = plans.stream_to_stacked(
-                        sj, stream, cand.scanned,
-                        min(p_stream, cand.rows.shape[1]))
-                else:
-                    res_p = plans.join_param_targets_all(
-                        ds, cand, p_in["targets"], p_in["param_field"],
-                        p_in["payload"], num_brokers,
-                        p_in["up_masks"] if pushdown else None, aggregated,
-                        p_in["domains"])
+                with jax.named_scope("bad.discover"):
+                    cand = discover(ds, index_state, p_static,
+                                    p_in["last_ts"], p_in["last_size"])
+                    if compact:
+                        stream = plans.compact_candidates(cand, p_stream)
+                        tot_p = stream.total
+                with jax.named_scope("bad.join"):
+                    if compact:
+                        sj = plans.join_param_stream(
+                            ds, stream, p_in["targets"], p_in["param_field"],
+                            p_in["payload"], num_brokers,
+                            p_in["up_masks"] if pushdown else None,
+                            aggregated, p_in["domains"], join_fn)
+                        res_p = plans.stream_to_stacked(
+                            sj, stream, cand.scanned,
+                            min(p_stream, cand.rows.shape[1]))
+                    else:
+                        res_p = plans.join_param_targets_all(
+                            ds, cand, p_in["targets"], p_in["param_field"],
+                            p_in["payload"], num_brokers,
+                            p_in["up_masks"] if pushdown else None,
+                            aggregated, p_in["domains"])
                 if deliver:
                     res_del = res_p
                     if stage is not None:
@@ -1931,21 +1941,24 @@ class BADEngine:
                         counts=p_in["targets"].counts,
                         ring=p_ring, epochs=p_in.get("epochs"))
             if s_static is not None:
-                cand = discover(ds, index_state, s_static,
-                                s_in["last_ts"], s_in["last_size"])
-                if compact:
-                    stream = plans.compact_candidates(cand, s_stream)
-                    tot_s = stream.total
-                    sj = plans.join_spatial_stream(
-                        ds, stream, s_in["locs"], s_in["brokers"], radii,
-                        s_in["payload"], num_brokers)
-                    res_s = plans.stream_to_stacked(
-                        sj, stream, cand.scanned,
-                        min(s_stream, cand.rows.shape[1]))
-                else:
-                    res_s = plans.join_spatial_all(
-                        ds, cand, s_in["locs"], s_in["brokers"], radii,
-                        s_in["payload"], num_brokers, spatial_fn)
+                with jax.named_scope("bad.discover"):
+                    cand = discover(ds, index_state, s_static,
+                                    s_in["last_ts"], s_in["last_size"])
+                    if compact:
+                        stream = plans.compact_candidates(cand, s_stream)
+                        tot_s = stream.total
+                with jax.named_scope("bad.join"):
+                    if compact:
+                        sj = plans.join_spatial_stream(
+                            ds, stream, s_in["locs"], s_in["brokers"], radii,
+                            s_in["payload"], num_brokers)
+                        res_s = plans.stream_to_stacked(
+                            sj, stream, cand.scanned,
+                            min(s_stream, cand.rows.shape[1]))
+                    else:
+                        res_s = plans.join_spatial_all(
+                            ds, cand, s_in["locs"], s_in["brokers"], radii,
+                            s_in["payload"], num_brokers, spatial_fn)
                 if deliver:
                     res_del = res_s
                     if stage is not None:
@@ -2054,7 +2067,17 @@ class BADEngine:
         Remaining host sync points, by design: the ``bad_index`` scan mode
         reads watermark deltas to bucket candidate shapes, and the compact
         backends read the live-candidate total for the grow-on-overflow
-        protocol (both documented in docs/ARCHITECTURE.md)."""
+        protocol (both documented in docs/ARCHITECTURE.md).
+
+        Host spans (``jax.profiler.TraceAnnotation``, free while no trace
+        runs): ``bad.dispatch`` around it, and per plan-group
+        ``bad.dispatch.group`` with ``bad.dispatch.bucket_read`` (the
+        ``bad_index`` bucket read), ``bad.dispatch.args`` (the call's
+        arguments and rings) and ``bad.dispatch.launch`` (the call)."""
+        with TraceAnnotation("bad.dispatch"):
+            return self._dispatch(request)
+
+    def _dispatch(self, request: plans.ExecutionRequest):
         from repro.core.runtime import PendingExecution
         deliver = request.deliver
         ordered = sorted(self.channels.values(), key=lambda s: s.index)
@@ -2105,11 +2128,12 @@ class BADEngine:
                                 tuple(st.spec.name for st in schs)))
             for k in [k for k in self._rings if k not in active]:
                 self._flush_ring(*self._rings.pop(k))
-        pending = [self._dispatch_plan_group(plan, param_chs, spatial_chs,
-                                             request.timed, deliver,
-                                             use_ring,
-                                             request.resolve_spills)
-                   for plan, (param_chs, spatial_chs) in groups.items()]
+        pending = []
+        for plan, (param_chs, spatial_chs) in groups.items():
+            with TraceAnnotation("bad.dispatch.group"):
+                pending.append(self._dispatch_plan_group(
+                    plan, param_chs, spatial_chs, request.timed, deliver,
+                    use_ring, request.resolve_spills))
         if request.advance:
             # watermark advance is a device-side functional update (no
             # sync); the in-flight calls captured the PRE-advance handle
@@ -2136,8 +2160,9 @@ class BADEngine:
             # shared shape bucket: the largest watermark delta across THIS
             # group's channels (two bulk host reads, not 2 device reads per
             # channel)
-            counts = np.asarray(self.index_state.counts)
-            wms = np.asarray(self.index_state.watermarks)
+            with TraceAnnotation("bad.dispatch.bucket_read"):
+                counts = np.asarray(self.index_state.counts)
+                wms = np.asarray(self.index_state.watermarks)
             pending = max(int(counts[st.index] - wms[st.index])
                           for st in chans)
             bucket = _pow2_bucket(pending, 6)
@@ -2151,74 +2176,81 @@ class BADEngine:
             p_layout = "slot" if plan.aggregation else "flat_slot"
         else:
             p_layout = plan.aggregation
-        p_names = tuple(st.spec.name for st in param_chs)
-        s_names = tuple(st.spec.name for st in spatial_chs)
-        p_in = s_in = p_ring = s_ring = None
-        if param_chs:
-            targets, up_masks, domains = self._stacked_inputs(
-                param_chs, plan.aggregation)
-            p_in = dict(
-                targets=targets, up_masks=up_masks, domains=domains,
-                param_field=jnp.asarray(
-                    [st.spec.param_field for st in param_chs], jnp.int32),
-                payload=jnp.asarray(
-                    [st.spec.payload_bytes for st in param_chs], jnp.int32),
-                last_ts=jnp.asarray(
-                    [st.last_exec_ts for st in param_chs], jnp.int32),
-                last_size=jnp.asarray(
-                    [st.last_exec_size for st in param_chs], jnp.int32))
-            if deliver:
-                p_in["sids"] = self._stacked_sids(param_chs, plan.aggregation)
-                if use_ring:
-                    p_ring = self._ring_in(
-                        ("param", plan, p_names), p_names, len(param_chs))
-                    p_in["epochs"] = jnp.asarray(
-                        [st.epoch for st in param_chs], jnp.int32)
-        if spatial_chs:
-            locs, ubrokers = self._stacked_spatial_inputs(spatial_chs)
-            s_in = dict(
-                locs=locs, brokers=ubrokers,
-                payload=jnp.asarray(
-                    [st.spec.payload_bytes for st in spatial_chs], jnp.int32),
-                last_ts=jnp.asarray(
-                    [st.last_exec_ts for st in spatial_chs], jnp.int32),
-                last_size=jnp.asarray(
-                    [st.last_exec_size for st in spatial_chs], jnp.int32))
-            if deliver:
-                s_in["sids"] = self._stacked_spatial_sids(spatial_chs)
-                if use_ring:
-                    s_ring = self._ring_in(
-                        ("spatial", plan, s_names), s_names,
-                        len(spatial_chs))
-                    s_in["epochs"] = jnp.asarray(
-                        [st.epoch for st in spatial_chs], jnp.int32)
-        args = (self.dataset, self.index_state, p_in, s_in, p_ring, s_ring)
-        t0 = time.perf_counter()
-        if plans.is_compact(plan.backend):
-            # the grow protocol reads the live total (documented sync
-            # point); rings are NOT donated — the loop re-presents them
-            res, wall = self._run_compact_group(
-                plan, param_chs, spatial_chs, max_cand, deliver, args, timed)
-        else:
-            donate = use_ring and (p_ring is not None or s_ring is not None)
-            fn, fkey = self._exec_all_fn(param_chs, spatial_chs, plan,
-                                         max_cand, deliver,
-                                         donate_rings=donate)
-            if timed:
-                # warming would CONSUME the donated rings: hand the warm
-                # call copies, dispatch the real call the originals
-                warm_args = args
-                if donate:
-                    cp = lambda r: (None if r is None
-                                    else jax.tree.map(jnp.copy, r))
-                    warm_args = args[:4] + (cp(p_ring), cp(s_ring))
-                self._warm_if_new(fkey, fn, warm_args)
-                t0 = time.perf_counter()
-            res = fn(*args)
-            wall = 0.0
-            if timed:
-                jax.block_until_ready(res)
-                wall = time.perf_counter() - t0
+        with TraceAnnotation("bad.dispatch.args"):
+            p_names = tuple(st.spec.name for st in param_chs)
+            s_names = tuple(st.spec.name for st in spatial_chs)
+            p_in = s_in = p_ring = s_ring = None
+            if param_chs:
+                targets, up_masks, domains = self._stacked_inputs(
+                    param_chs, plan.aggregation)
+                p_in = dict(
+                    targets=targets, up_masks=up_masks, domains=domains,
+                    param_field=jnp.asarray(
+                        [st.spec.param_field for st in param_chs], jnp.int32),
+                    payload=jnp.asarray(
+                        [st.spec.payload_bytes for st in param_chs],
+                        jnp.int32),
+                    last_ts=jnp.asarray(
+                        [st.last_exec_ts for st in param_chs], jnp.int32),
+                    last_size=jnp.asarray(
+                        [st.last_exec_size for st in param_chs], jnp.int32))
+                if deliver:
+                    p_in["sids"] = self._stacked_sids(param_chs,
+                                                      plan.aggregation)
+                    if use_ring:
+                        p_ring = self._ring_in(
+                            ("param", plan, p_names), p_names, len(param_chs))
+                        p_in["epochs"] = jnp.asarray(
+                            [st.epoch for st in param_chs], jnp.int32)
+            if spatial_chs:
+                locs, ubrokers = self._stacked_spatial_inputs(spatial_chs)
+                s_in = dict(
+                    locs=locs, brokers=ubrokers,
+                    payload=jnp.asarray(
+                        [st.spec.payload_bytes for st in spatial_chs],
+                        jnp.int32),
+                    last_ts=jnp.asarray(
+                        [st.last_exec_ts for st in spatial_chs], jnp.int32),
+                    last_size=jnp.asarray(
+                        [st.last_exec_size for st in spatial_chs], jnp.int32))
+                if deliver:
+                    s_in["sids"] = self._stacked_spatial_sids(spatial_chs)
+                    if use_ring:
+                        s_ring = self._ring_in(
+                            ("spatial", plan, s_names), s_names,
+                            len(spatial_chs))
+                        s_in["epochs"] = jnp.asarray(
+                            [st.epoch for st in spatial_chs], jnp.int32)
+            args = (self.dataset, self.index_state, p_in, s_in, p_ring, s_ring)
+        with TraceAnnotation("bad.dispatch.launch"):
+            t0 = time.perf_counter()
+            if plans.is_compact(plan.backend):
+                # the grow protocol reads the live total (documented sync
+                # point); rings are NOT donated — the loop re-presents them
+                res, wall = self._run_compact_group(
+                    plan, param_chs, spatial_chs, max_cand, deliver, args,
+                    timed)
+            else:
+                donate = use_ring and (p_ring is not None
+                                       or s_ring is not None)
+                fn, fkey = self._exec_all_fn(param_chs, spatial_chs, plan,
+                                             max_cand, deliver,
+                                             donate_rings=donate)
+                if timed:
+                    # warming would CONSUME the donated rings: hand the warm
+                    # call copies, dispatch the real call the originals
+                    warm_args = args
+                    if donate:
+                        cp = lambda r: (None if r is None
+                                        else jax.tree.map(jnp.copy, r))
+                        warm_args = args[:4] + (cp(p_ring), cp(s_ring))
+                    self._warm_if_new(fkey, fn, warm_args)
+                    t0 = time.perf_counter()
+                res = fn(*args)
+                wall = 0.0
+                if timed:
+                    jax.block_until_ready(res)
+                    wall = time.perf_counter() - t0
         del_p, del_s = res[2], res[3]
         if use_ring:
             # persist the successor rings AT DISPATCH (device-resident
@@ -2248,7 +2280,12 @@ class BADEngine:
         already packed/fanned out every channel, so the host only pushes
         spills and reads (C,)-shaped counters. ``wall_time_s`` is the timed
         fused wall amortized per channel, or (untimed) the
-        dispatch-to-materialize latency share."""
+        dispatch-to-materialize latency share.
+
+        Host spans: ``bad.sync.wait`` (the block on the call), then per join
+        group ``bad.sync.copy``, ``bad.sync.spill`` and ``bad.sync.report``.
+        The report span carries the send stage's ``notify_slots`` (slots
+        searched, ``C * max_notify``) and ``produced_sids`` as arguments."""
         res_p, res_s, del_p, del_s, _tots, ranks = g.res
         rank_p, rank_s = ranks
         wall = g.wall
@@ -2257,7 +2294,8 @@ class BADEngine:
             # totals scalars stand in for the whole call — blocking on the
             # full tree would touch the successor ring handle, which the
             # NEXT dispatch may already have consumed (donated)
-            jax.block_until_ready(_tots)
+            with TraceAnnotation("bad.sync.wait"):
+                jax.block_until_ready(_tots)
             wall = time.perf_counter() - g.t0
         share = wall / max(len(g.param_chs) + len(g.spatial_chs), 1)
         for chs, res, dlv, layout, epochs, sids, rank in (
@@ -2267,29 +2305,42 @@ class BADEngine:
                  g.s_sids, rank_s)):
             if not chs:
                 continue
-            host = jax.tree.map(np.asarray, res)
-            stats = (self._spill_and_stats(
-                chs, layout, dlv, epochs=epochs,
-                resolve_tables=None if sids is None else np.asarray(sids),
-                ranked=None if rank is None else
-                tuple(np.asarray(x) for x in rank))
-                if g.deliver else {})
-            pay = noti = None
-            if g.deliver and self.debug_delivery_buffers:
-                pay = np.asarray(dlv.pack.payload)
-                noti = np.asarray(dlv.fan.notify)
-            for i, st in enumerate(chs):
-                reports[st.spec.name] = ExecutionReport(
-                    channel=st.spec.name, flags=g.plan.flags, plan=g.plan,
-                    result=jax.tree.map(lambda a, i=i: a[i], host),
-                    wall_time_s=share,
-                    num_results=int(host.num_results[i]),
-                    num_notified=int(host.num_notified[i]),
-                    scanned=int(host.scanned[i]),
-                    broker_bytes=host.broker_bytes[i],
-                    overflow=stats.get(st.spec.name),
-                    payload=None if pay is None else pay[i],
-                    notify=None if noti is None else noti[i])
+            with TraceAnnotation("bad.sync.copy"):
+                host = jax.tree.map(np.asarray, res)
+                pay = noti = None
+                if g.deliver and self.debug_delivery_buffers:
+                    pay = np.asarray(dlv.pack.payload)
+                    noti = np.asarray(dlv.fan.notify)
+            stats = {}
+            if g.deliver:
+                with TraceAnnotation("bad.sync.spill"):
+                    stats = self._spill_and_stats(
+                        chs, layout, dlv, epochs=epochs,
+                        resolve_tables=(None if sids is None
+                                        else np.asarray(sids)),
+                        ranked=None if rank is None else
+                        tuple(np.asarray(x) for x in rank))
+            # the send stage's searched slots and produced sIDs (all of it
+            # delivered, spilled or dropped, ranked drops aside): its share
+            # of useful work, read from the trace
+            counters = {} if not g.deliver else dict(
+                notify_slots=len(chs) * self.max_notify,
+                produced_sids=sum(d.delivered_sids + d.overflow_sids
+                                  - d.ranked_sids for d in stats.values()))
+            with TraceAnnotation("bad.sync.report", **counters):
+                for i, st in enumerate(chs):
+                    reports[st.spec.name] = ExecutionReport(
+                        channel=st.spec.name, flags=g.plan.flags,
+                        plan=g.plan,
+                        result=jax.tree.map(lambda a, i=i: a[i], host),
+                        wall_time_s=share,
+                        num_results=int(host.num_results[i]),
+                        num_notified=int(host.num_notified[i]),
+                        scanned=int(host.scanned[i]),
+                        broker_bytes=host.broker_bytes[i],
+                        overflow=stats.get(st.spec.name),
+                        payload=None if pay is None else pay[i],
+                        notify=None if noti is None else noti[i])
 
     def _run_compact_group(self, plan: plans.ChannelPlan,
                            param_chs: List[ChannelState],

@@ -31,9 +31,10 @@ same-channel churn during sustained overflow.
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Dict, List, Optional, Protocol, Tuple, runtime_checkable
+
+from jax.profiler import TraceAnnotation
 
 
 @runtime_checkable
@@ -91,16 +92,14 @@ class PendingExecution:
 
     ``sync()`` is idempotent: the first call blocks on the device results,
     runs the host half (report assembly, SpillQueue pushes, conserving
-    DeliveryStats) and caches the reports; later calls return them.
-    ``latency_s`` records the dispatch-to-materialize latency of the first
-    sync."""
+    DeliveryStats) and caches the reports; later calls return them. The
+    first sync runs in a ``bad.sync`` host span with one
+    ``bad.sync.group`` per plan-group."""
 
     def __init__(self, engine, groups: List):
         self._engine = engine
         self._groups = groups
         self._reports: Optional[Dict] = None
-        self._t0 = time.perf_counter()
-        self.latency_s: Optional[float] = None
 
     @property
     def done(self) -> bool:
@@ -109,9 +108,10 @@ class PendingExecution:
     def sync(self) -> Dict:
         if self._reports is None:
             reports: Dict = {}
-            for g in self._groups:
-                self._engine._materialize_group(g, reports)
-            self.latency_s = time.perf_counter() - self._t0
+            with TraceAnnotation("bad.sync"):
+                for g in self._groups:
+                    with TraceAnnotation("bad.sync.group"):
+                        self._engine._materialize_group(g, reports)
             self._reports = reports
         return self._reports
 
@@ -134,8 +134,8 @@ class TickPipeline:
     oldest first — possibly empty while the window fills. ``flush()``
     syncs everything still in flight (end of run, or before an operation
     that must observe a quiesced engine). ``max_in_flight`` is the measured
-    pipeline depth actually achieved; ``latencies`` the per-tick
-    dispatch-to-materialize seconds."""
+    pipeline depth actually achieved. ``step`` and ``flush`` run in the
+    ``bad.step`` and ``bad.flush`` host spans."""
 
     def __init__(self, engine: EngineProtocol, depth: int = 2,
                  drain_every: Optional[int] = None):
@@ -147,7 +147,6 @@ class TickPipeline:
         self._window: deque = deque()   # (tick_number, PendingExecution)
         self._tick = 0
         self.max_in_flight = 0
-        self.latencies: List[float] = []
 
     @property
     def in_flight(self) -> int:
@@ -156,28 +155,27 @@ class TickPipeline:
     def step(self, flags=None, deliver: bool = True,
              timed: bool = False) -> List[Tuple[int, Dict]]:
         """Dispatch one tick; sync (only) what the depth bound forces out."""
-        pend = self.engine.dispatch_all(flags, timed=timed, deliver=deliver,
-                                        resolve_spills=True)
-        self._window.append((self._tick, pend))
-        self._tick += 1
-        # the dispatch just issued overlaps with every older in-flight tick
-        self.max_in_flight = max(self.max_in_flight, len(self._window))
-        out: List[Tuple[int, Dict]] = []
-        while len(self._window) > self.depth - 1:
-            t, p = self._window.popleft()
-            out.append((t, p.sync()))
-            if p.latency_s is not None:
-                self.latencies.append(p.latency_s)
-        return out
+        with TraceAnnotation("bad.step"):
+            pend = self.engine.dispatch_all(flags, timed=timed,
+                                            deliver=deliver,
+                                            resolve_spills=True)
+            self._window.append((self._tick, pend))
+            self._tick += 1
+            # the new dispatch overlaps with every older in-flight tick
+            self.max_in_flight = max(self.max_in_flight, len(self._window))
+            out: List[Tuple[int, Dict]] = []
+            while len(self._window) > self.depth - 1:
+                t, p = self._window.popleft()
+                out.append((t, p.sync()))
+            return out
 
     def flush(self) -> List[Tuple[int, Dict]]:
         """Sync every in-flight tick, oldest first."""
         out: List[Tuple[int, Dict]] = []
-        while self._window:
-            t, p = self._window.popleft()
-            out.append((t, p.sync()))
-            if p.latency_s is not None:
-                self.latencies.append(p.latency_s)
+        with TraceAnnotation("bad.flush"):
+            while self._window:
+                t, p = self._window.popleft()
+                out.append((t, p.sync()))
         return out
 
     def drain_due(self) -> bool:
