@@ -17,11 +17,13 @@ Two delivery paths share the same single-channel kernels:
                  every channel's convert+send in ONE jitted computation over
                  the stacked channel axis, with per-channel caps and one-hot
                  per-broker accounting, so delivery runs inside the SAME
-                 device program as execution. The fused stages are
-                 gather-formulated (each output slot binary-searches its
-                 source pair in per-channel prefix sums), so the work is
-                 proportional to the delivery capacity + total overflow, not
-                 to the C x max-pending x member-cap padded grid. Overflowed
+                 device program as execution. The send stage marks each
+                 pair's run start in the notify buffer and fills the runs by
+                 a prefix max; the convert stage and the overflow tails
+                 binary-search their source pair in per-channel prefix sums.
+                 The work is proportional to the delivery capacity + total
+                 overflow + the C x max-pending pair grid, not to the
+                 C x max-pending x member-cap member grid. Overflowed
                  pairs/sIDs land in the device-resident ``RetryRing`` (when
                  the caller passes one — re-packed and re-delivered ahead of
                  the fresh result on the NEXT call, epoch-masked staleness)
@@ -266,16 +268,22 @@ def fanout_sids(result: ChannelResult, group_sids: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# ---------------------------------------------------------------------------
 # fused multi-channel delivery: one jitted call covers every channel's
 # convert+send, so execution and delivery share a single device program.
 #
-# Formulation: GATHER, not scatter. Each output slot (payload line, notify
-# slot, spill slot) locates its source pair by binary search over per-channel
-# prefix sums, so the work is proportional to the DELIVERY CAPACITY
-# (C x (max_pairs + max_notify) + spill) — never to the shape-bucketed
-# C x max-pending x member-cap grid the stacked results are padded to. The
-# only full-grid passes are O(C x P) elementwise counts/prefix sums.
+# Formulation: the work is proportional to the DELIVERY CAPACITY
+# (C x (max_pairs + max_notify) + spill) plus O(C x P) passes over the pair
+# grid — never to the C x P x member-cap member grid the stacked results
+# would expand to.
+#   send (the whole notify buffer): each non-empty pair SCATTERS its index
+#     at the slot its run of members starts at — one scatter over the
+#     C x P pair grid, of the order of the member counts and prefix sums
+#     the stage already takes — and a prefix max along the slots fills each
+#     run with its owner (``_dense_ranks``). The scatter never runs over
+#     the member grid.
+#   convert lines, ring tails, spill slots (short sets of ranks): each
+#     output slot binary-searches its source pair in the per-channel prefix
+#     sums and gathers it.
 # ---------------------------------------------------------------------------
 
 
@@ -494,10 +502,11 @@ def fanout_sids_all(result: ChannelResult, group_sids: jnp.ndarray,
                     max_notify: int,
                     caps: Optional[jnp.ndarray] = None,
                     counts: Optional[jnp.ndarray] = None) -> FanoutDelivery:
-    """Send stage for EVERY channel at once, with per-channel caps. Each
-    notify slot binary-searches its source pair in the per-channel member
-    prefix sums and gathers the sID directly — O(max_notify log P) per
-    channel, no member grid. Delivered prefixes are bit-identical to
+    """Send stage for EVERY channel at once, with per-channel caps. The
+    notify buffer is resolved densely (``_dense_ranks``): each pair marks
+    the slot its run of members starts at and a prefix max fills the run,
+    so the work is O(C * (P + max_notify)), with no member grid and no
+    per-slot search. Delivered prefixes are bit-identical to
     ``fanout_sids`` per channel (tables pack members as a -1-padded prefix).
     ``counts`` (C, T): engine-maintained member counts (see
     ``_member_counts``)."""
@@ -506,33 +515,85 @@ def fanout_sids_all(result: ChannelResult, group_sids: jnp.ndarray,
 
 def _fanout_parts(result: ChannelResult, group_sids: jnp.ndarray,
                   max_notify: int, caps,
-                  counts: Optional[jnp.ndarray] = None):
+                  counts: Optional[jnp.ndarray] = None,
+                  resident: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None):
     """The send stage plus its internal member bookkeeping, so ``deliver_all``
-    can resolve spill slots against the same prefix sums without
-    re-deriving them."""
+    can resolve spill and ring-tail ranks against the same prefix sums
+    without re-deriving them. ``resident`` — (values (C, W), count (C,)),
+    a ring's compacted sID prefix — is delivered ahead of the fresh members:
+    combined rank k < count reads ``values[:, k]``, rank k >= count fresh
+    member k - count. Returns the delivery and (tgt2, members, cumm, cap)."""
     C = result.pair_valid.shape[0]
     valid2, _, tgt2, _, _, cap_n = _pair_layout(result, caps, max_notify)
     members = _member_counts(group_sids, valid2, tgt2, counts)  # (C, P)
     cumm = jnp.cumsum(members, axis=1)
-    produced = cumm[:, -1]
+    shift = (jnp.zeros((C,), jnp.int32) if resident is None
+             else resident[1])
+    produced = shift + cumm[:, -1]
     delivered = jnp.minimum(produced, cap_n)
-    k = jnp.broadcast_to(jnp.arange(max_notify, dtype=jnp.int32),
-                         (C, max_notify))
-    notify = _member_lookup(group_sids, tgt2, members, cumm, k,
-                            k < delivered[:, None])
+    p, j = _dense_ranks(members, cumm, shift, max_notify)
+    vals = _pair_member(group_sids, tgt2, p, j)
+    k = jnp.arange(max_notify, dtype=jnp.int32)[None, :]
+    if resident is not None:
+        values, count = resident
+        W = values.shape[1]
+        head = (values[:, :max_notify] if W >= max_notify else
+                jnp.pad(values, ((0, 0), (0, max_notify - W)),
+                        constant_values=-1))
+        vals = jnp.where(k < count[:, None], head, vals)
+    notify = jnp.where(k < delivered[:, None], vals, -1)
     return FanoutDelivery(notify, delivered, produced), (tgt2, members, cumm,
                                                          cap_n)
 
 
-def _member_lookup(group_sids, tgt2, members, cumm, k, ok) -> jnp.ndarray:
-    """Resolve per-channel member ranks ``k`` (C, Q) to sIDs: binary-search
-    the owning pair, derive the in-pair offset, gather. -1 where not ``ok``."""
-    P = tgt2.shape[1]
-    ch = jnp.arange(tgt2.shape[0], dtype=jnp.int32)[:, None]
+def _dense_ranks(members: jnp.ndarray, cumm: jnp.ndarray,
+                 shift: jnp.ndarray, Q: int
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Owner pair and in-pair offset of EVERY rank k in [0, Q), per channel,
+    where pair p's members hold ranks [s_p, s_p + members[p]) with
+    s_p = shift + cumm[p] - members[p]. Each non-empty pair writes its index
+    at s_p (one scatter over the (C, P) pair grid: empty pairs and starts
+    past Q fall to distinct out-of-range slots, so the indices are unique),
+    and a prefix max along the slots carries it over its run; a second
+    prefix max over the marked slots gives each slot its run's start.
+    Slots before the first run (a ring's resident prefix) read owner 0,
+    slots past the last run its owner and an offset past its members: the
+    caller masks both."""
+    C, P = cumm.shape
+    ch = jnp.arange(C, dtype=jnp.int32)[:, None]
+    pid = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (C, P))
+    start = shift[:, None] + cumm - members
+    dest = jnp.where((members > 0) & (start < Q), start, Q + pid)
+    marks = jnp.full((C, Q), -1, jnp.int32).at[ch, dest].set(
+        pid, mode="drop", unique_indices=True)
+    k = jnp.arange(Q, dtype=jnp.int32)[None, :]
+    owner = jax.lax.cummax(marks, axis=1)
+    run = jax.lax.cummax(jnp.where(marks >= 0, k, -1), axis=1)
+    return jnp.maximum(owner, 0), k - run
+
+
+def _search_ranks(members: jnp.ndarray, cumm: jnp.ndarray, k: jnp.ndarray
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Owner pair and in-pair offset of per-channel member ranks ``k``
+    (C, Q) by binary search over the member prefix sums: the form for a
+    short set of sparse ranks (the ring tail)."""
+    P = cumm.shape[1]
     p = jnp.minimum(_source_pair(cumm, k), P - 1)
-    j = k - (_gather(cumm, p) - _gather(members, p))           # rank in pair
+    return p, k - (_gather(cumm, p) - _gather(members, p))
+
+
+def _pair_member(group_sids, tgt2, p, j) -> jnp.ndarray:
+    """sID of member ``j`` of stacked pair ``p`` (both (C, Q))."""
+    ch = jnp.arange(tgt2.shape[0], dtype=jnp.int32)[:, None]
     tgt_safe = jnp.maximum(_gather(tgt2, p), 0)
-    return jnp.where(ok, _member_value(group_sids, ch, tgt_safe, j), -1)
+    return _member_value(group_sids, ch, tgt_safe, j)
+
+
+def _member_lookup(group_sids, tgt2, members, cumm, k, ok) -> jnp.ndarray:
+    """Resolve sparse per-channel member ranks ``k`` (C, Q) to sIDs by
+    search. -1 where not ``ok``."""
+    p, j = _search_ranks(members, cumm, k)
+    return jnp.where(ok, _pair_member(group_sids, tgt2, p, j), -1)
 
 
 def deliver_all(result: ChannelResult, group_sids: jnp.ndarray,
@@ -621,9 +682,11 @@ def _deliver_with_ring(result: ChannelResult, group_sids: jnp.ndarray,
     (epoch-matching) ring entries in residence order, then the fresh valid
     pairs in ravel order. The live overflow tail — ranks past the cap —
     re-enters the output ring (first W entries), then the spill stream
-    (next spill_cap), then truncates to counted drops. Everything is
-    gather-formulated against the ring's live prefix sums and the fresh
-    prefix sums, so the added work is O(C * (W + max_pairs + spill_cap))."""
+    (next spill_cap), then truncates to counted drops. The wire lines and
+    the tails are resolved by search against the ring's live prefix sums
+    and the fresh prefix sums; the notify buffer reads the resident sIDs
+    as its first slots and resolves the fresh members densely behind them,
+    so the added work is O(C * (W + max_pairs + spill_cap))."""
     C = result.pair_valid.shape[0]
     W = ring.window
     epochs = jnp.asarray(epochs, jnp.int32)
@@ -691,27 +754,23 @@ def _deliver_with_ring(result: ChannelResult, group_sids: jnp.ndarray,
 
     # ---- sids lane ------------------------------------------------------
     with jax.named_scope("bad.send"):
-        fan0, (tgt2, members, cumm, cap_n) = _fanout_parts(
-            result, group_sids, max_notify, caps_notify, counts)
         rsc = ring.sid_count
-        produced_s = rsc + fan0.produced
-        delivered_s = jnp.minimum(produced_s, cap_n)
+        fan, (tgt2, members, cumm, cap_n) = _fanout_parts(
+            result, group_sids, max_notify, caps_notify, counts,
+            resident=(ring.sid_values, rsc))
+        produced_s, delivered_s = fan.produced, fan.delivered
+    with jax.named_scope("bad.ring"):
 
         def comb_sids(k, ok):
-            """sIDs for combined-order ranks ``k`` (C, Q): resident ring
-            sids (a compacted prefix: direct index) first, fresh members
-            after."""
+            """sIDs for the sparse combined-order ranks ``k`` (C, W) of
+            the ring tail: resident ring sids (a compacted prefix: direct
+            index) first, fresh members after, by search."""
             from_ring = k < rsc[:, None]
             r_val = _gather(ring.sid_values, jnp.minimum(k, W - 1))
             kf = jnp.maximum(k - rsc[:, None], 0)
             f_val = _member_lookup(group_sids, tgt2, members, cumm, kf, ok)
             return jnp.where(ok, jnp.where(from_ring, r_val, f_val), -1)
 
-        k = jnp.broadcast_to(jnp.arange(max_notify, dtype=jnp.int32),
-                             (C, max_notify))
-        notify = comb_sids(k, k < delivered_s[:, None])
-        fan = FanoutDelivery(notify, delivered_s, produced_s)
-    with jax.named_scope("bad.ring"):
         ov_s = produced_s - delivered_s
         ok_snew = i_new < jnp.minimum(ov_s, W)[:, None]
         nsids = comb_sids(delivered_s[:, None] + i_new, ok_snew)

@@ -2284,8 +2284,9 @@ class BADEngine:
 
         Host spans: ``bad.sync.wait`` (the block on the call), then per join
         group ``bad.sync.copy``, ``bad.sync.spill`` and ``bad.sync.report``.
-        The report span carries the send stage's ``notify_slots`` (slots
-        searched, ``C * max_notify``) and ``produced_sids`` as arguments."""
+        The report span carries the send stage's ``notify_slots`` (the
+        notify buffer's slots, ``C * max_notify``) and ``produced_sids`` as
+        arguments."""
         res_p, res_s, del_p, del_s, _tots, ranks = g.res
         rank_p, rank_s = ranks
         wall = g.wall
@@ -2320,7 +2321,7 @@ class BADEngine:
                                         else np.asarray(sids)),
                         ranked=None if rank is None else
                         tuple(np.asarray(x) for x in rank))
-            # the send stage's searched slots and produced sIDs (all of it
+            # the send stage's notify slots and produced sIDs (all of it
             # delivered, spilled or dropped, ranked drops aside): its share
             # of useful work, read from the trace
             counters = {} if not g.deliver else dict(

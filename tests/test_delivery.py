@@ -342,3 +342,146 @@ def test_deliver_false_leaves_no_trace(rng):
     assert all(r.overflow is None for r in reps.values())
     assert eng.spill.pending_pairs() + eng.spill.pending_sids() == 0
     assert not eng.drain_spilled()
+
+
+# ---------------------------------------------------------------------------
+# send stage: dense notify-buffer resolution vs the kept search
+# ---------------------------------------------------------------------------
+
+
+def _send_case(rng, C, identity, n_rows=6, max_t=3, n_groups=5, cap=4):
+    """Random stacked result whose pairs include zero-member ones: invalid
+    pairs, empty groups (group tables) and negative targets (identity
+    fanout). Returns the result, the sID table and each channel's member
+    stream in delivery order."""
+    import jax.numpy as jnp
+    from repro.core.plans import ChannelResult
+    valid = rng.random((C, n_rows, max_t)) < 0.6
+    rows = rng.integers(0, 1000, (C, n_rows, max_t)).astype(np.int32)
+    if identity:
+        tgts = rng.integers(-1, 40, (C, n_rows, max_t)).astype(np.int32)
+        group_sids = np.zeros((C, 0), np.int32)
+    else:
+        tgts = rng.integers(0, n_groups, (C, n_rows, max_t)).astype(np.int32)
+        counts = rng.integers(0, cap + 1, (C, n_groups))
+        counts[:, 0] = 0                       # at least one empty group
+        group_sids = np.full((C, n_groups, cap), -1, np.int32)
+        for c in range(C):
+            for g in range(n_groups):
+                group_sids[c, g, :counts[c, g]] = rng.integers(
+                    0, 10000, counts[c, g])
+    streams = []
+    for c in range(C):
+        v, t = valid[c].ravel(), tgts[c].ravel()
+        if identity:
+            streams.append(t[v & (t >= 0)])
+        else:
+            m = group_sids[c][t[v]]
+            streams.append(m[m >= 0])
+    z = jnp.zeros((C,), jnp.int32)
+    res = ChannelResult(jnp.asarray(rows), jnp.asarray(tgts),
+                        jnp.asarray(valid), jnp.asarray(rows[:, :, 0]),
+                        jnp.asarray(valid[:, :, 0]), z, z, z,
+                        jnp.zeros((C, 1), jnp.int32),
+                        jnp.zeros((C, 1), jnp.int32))
+    return res, group_sids, streams
+
+
+SEND_CASES = {   # channels, resident ring sIDs (max), notify size, caps
+    "plain": (1, 0, "fits", False),
+    "resident": (2, 4, "fits", False),
+    "capped": (3, 0, "fits", True),
+    "resident_capped": (2, 6, "fits", True),
+    "past_q": (2, 0, "short", False),
+    "resident_past_q": (3, 3, "short", True),
+}
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("identity", [False, True], ids=["groups", "identity"])
+@pytest.mark.parametrize("case", sorted(SEND_CASES))
+def test_dense_send_matches_search(case, identity, seed):
+    """The send stage's dense resolution (a scatter of run starts and a
+    prefix max) gives every rank the owner pair, in-pair offset and sID the
+    kept binary search (``_member_lookup``) gives it, and the whole notify
+    buffer — resident ring sIDs first, caps and ranks past the buffer
+    included — equals the member stream expanded in numpy."""
+    import jax.numpy as jnp
+    from repro.core import broker
+    C, shift_max, q, capped = SEND_CASES[case]
+    rng = np.random.default_rng(1000 * seed + sorted(SEND_CASES).index(case))
+    res, group_sids, streams = _send_case(rng, C, identity)
+    gs = jnp.asarray(group_sids)
+    longest = max(len(s) for s in streams) + shift_max
+    Q = max(1, longest // 2) if q == "short" else longest + 5
+    if q == "short":
+        assert longest > Q                     # produced past the buffer
+    shift = rng.integers(0, shift_max + 1, C).astype(np.int32)
+    shift[0] = shift_max
+    caps = None
+    if capped:
+        caps = np.array([max(0, len(s) + int(r) - 2) for s, r
+                         in zip(streams, shift)], np.int32)
+
+    valid2 = res.pair_valid.reshape(C, -1)
+    tgt2 = res.pair_targets.reshape(C, -1)
+    members = broker._member_counts(gs, valid2, tgt2)
+    cumm = jnp.cumsum(members, axis=1)
+    p_d, j_d = broker._dense_ranks(members, cumm, jnp.asarray(shift), Q)
+    k = np.arange(Q)[None, :]
+    kf = k - shift[:, None]
+    ok = (kf >= 0) & (kf < np.asarray(cumm[:, -1])[:, None])
+    p_s, j_s = broker._search_ranks(members, cumm,
+                                    jnp.asarray(np.maximum(kf, 0)))
+    np.testing.assert_array_equal(np.asarray(p_d)[ok], np.asarray(p_s)[ok])
+    np.testing.assert_array_equal(np.asarray(j_d)[ok], np.asarray(j_s)[ok])
+    want = broker._member_lookup(gs, tgt2, members, cumm,
+                                 jnp.asarray(np.maximum(kf, 0)),
+                                 jnp.asarray(ok))
+    got = jnp.where(jnp.asarray(ok),
+                    broker._pair_member(gs, tgt2, p_d, j_d), -1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    W = max(shift_max, 1)
+    ring_vals = np.full((C, W), -1, np.int32)
+    for c in range(C):
+        ring_vals[c, :shift[c]] = rng.integers(20000, 30000, shift[c])
+    fan, _ = broker._fanout_parts(
+        res, gs, Q, None if caps is None else jnp.asarray(caps),
+        resident=(jnp.asarray(ring_vals), jnp.asarray(shift)))
+    for c in range(C):
+        full = np.concatenate([ring_vals[c, :shift[c]], streams[c]])
+        cap_c = Q if caps is None else min(int(caps[c]), Q)
+        n = min(len(full), cap_c)
+        exp = np.full(Q, -1, np.int32)
+        exp[:n] = full[:n]
+        assert int(fan.produced[c]) == len(full)
+        assert int(fan.delivered[c]) == n
+        np.testing.assert_array_equal(np.asarray(fan.notify[c]), exp)
+
+
+@pytest.mark.parametrize("with_ring", [False, True], ids=["plain", "ring"])
+def test_send_stage_compiles_without_search(rng, with_ring):
+    """No ``while`` (the binary search's loop) carries the ``bad.send``
+    scope in the compiled delivery program, with or without a ring; the
+    sparse lookups of the overflow tails keep their search under
+    ``bad.ring``."""
+    import re
+
+    import jax
+    from repro.core import broker
+    stacked, group_sids, _, _ = random_stacked_broker_result(rng, 2, 8, 4,
+                                                             6, 3)
+    ring = broker.empty_ring(2, 16) if with_ring else None
+    epochs = jnp.zeros((2,), jnp.int32) if with_ring else None
+
+    def deliver(res, sids):
+        return broker.deliver_all(res, sids, 2, 16, 32, 8, ring=ring,
+                                  epochs=epochs)
+
+    text = jax.jit(deliver).lower(stacked,
+                                  jnp.asarray(group_sids)).compile().as_text()
+    loops = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines() if re.search(r"\bwhile\(", line)]
+    assert not [name for name in loops if "/bad.send/" in name], loops
+    assert [name for name in loops if "/bad.ring/" in name], loops

@@ -303,3 +303,55 @@ def test_ring_counts_pass_matches_table_derivation(rng):
     np.testing.assert_array_equal(np.asarray(fa.notify), np.asarray(fb.notify))
     np.testing.assert_array_equal(np.asarray(fa.produced),
                                   np.asarray(fb.produced))
+
+
+@pytest.mark.parametrize("identity", [False, True], ids=["groups", "identity"])
+def test_ring_resident_sids_lead_the_notify_buffer(rng, identity):
+    """Ring-aware ``deliver_all`` with resident ring sIDs: per channel, the
+    notify buffer holds the resident sIDs first, then the fresh members in
+    the order the per-channel ``fanout_sids`` delivers them, cut at the
+    cap; the overflow tail refills the output ring, then the spill
+    stream."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.broker import deliver_all, empty_ring, fanout_sids
+    from conftest import random_stacked_broker_result
+    C, W, max_notify, spill_cap = 3, 8, 24, 4
+    stacked, group_sids, _, _ = random_stacked_broker_result(rng, C, 12, 3,
+                                                             5, 3)
+    if identity:
+        group_sids = np.zeros((C, 0), np.int32)
+    gs = jnp.asarray(group_sids)
+    rsc = np.array([W, 3, 0], np.int32)
+    sid_values = np.full((C, W), -1, np.int32)
+    for c in range(C):
+        sid_values[c, :rsc[c]] = rng.integers(50000, 60000, rsc[c])
+    ring = empty_ring(C, W)._replace(sid_values=jnp.asarray(sid_values),
+                                     sid_count=jnp.asarray(rsc))
+    streams = []
+    for c in range(C):
+        one = jax.tree.map(lambda a, c=c: a[c], stacked)
+        big, dlv, _ = fanout_sids(one, gs[c], 4096)
+        streams.append(np.concatenate([sid_values[c, :rsc[c]],
+                                       np.asarray(big)[:int(dlv)]]))
+    caps = np.array([max(1, len(s) - W - 3) for s in streams], np.int32)
+    d = deliver_all(stacked, gs, 2, 16, max_notify, spill_cap,
+                    caps_notify=jnp.asarray(caps), ring=ring,
+                    epochs=jnp.zeros((C,), jnp.int32))
+    spill_ch = np.asarray(d.sid_spill.channels)
+    spill_vals = np.asarray(d.sid_spill.values)
+    for c, full in enumerate(streams):
+        n = min(len(full), int(caps[c]), max_notify)
+        exp = np.full(max_notify, -1, np.int32)
+        exp[:n] = full[:n]
+        np.testing.assert_array_equal(np.asarray(d.fan.notify[c]), exp)
+        assert int(d.fan.delivered[c]) == n
+        assert int(d.fan.produced[c]) == len(full)
+        tail = full[n:]
+        r = int(d.ring.sid_count[c])
+        assert r == min(len(tail), W)
+        np.testing.assert_array_equal(np.asarray(d.ring.sid_values[c, :r]),
+                                      tail[:r])
+        np.testing.assert_array_equal(spill_vals[spill_ch == c],
+                                      tail[W:W + spill_cap])
+    assert (spill_ch >= 0).any()

@@ -6,19 +6,25 @@ ingest-time ``predicate_filter``, the fused executor's vmapped
 ``predicate_filter_rows`` and channel-stacked ``spatial_match``, and the
 compacted join's ``join_compact`` — and asserts the compiled program holds
 the Mosaic kernel (``tpu_custom_call``) rather than interpreted XLA ops.
-Nothing runs: this catches what the TPU compiler refuses (unaligned
-blocks, too much VMEM) without a chip.
+The broker's ring-aware delivery compiles at a deployment's spatial shape
+with no binary-search loop in its send stage. Nothing runs: this catches
+what the TPU compiler refuses (unaligned blocks, too much VMEM) without a
+chip.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file. Keep all such compiles in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import broker
 from repro.core import records as R
+from repro.core.plans import ChannelResult
 from repro.core.channel import tweets_about_crime, tweets_about_drugs
 from repro.core.predicates import compile_conditions
 from repro.kernels.join_compact import ops as jc_ops
@@ -102,3 +108,34 @@ def test_join_compact_compiles(compile_for_tpu, aggregated):
         ((s, max_t), jnp.int32), ((s,), jnp.int32), ((s, max_t), jnp.int32),
         ((s, max_t), jnp.int32), ((s,), jnp.int32), ((s,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_send_stage_compiles_without_search(compile_for_tpu):
+    """Ring-aware delivery of one spatial channel at the §5.1 cell's shape:
+    128 candidate rows x 16,384 located users (P = 2,097,152 pairs) into
+    8,388,608 notify slots. No ``while`` carries ``bad.send``; the ring
+    tail and spill lookups keep their search under ``bad.ring``."""
+    rows, users, max_notify, window = 128, 16384, 8388608, 4096
+
+    def deliver(pair_rows, pair_targets, pair_valid, brokers, ring_cw,
+                ring_c):
+        z = jnp.zeros((1,), jnp.int32)
+        res = ChannelResult(pair_rows, pair_targets, pair_valid,
+                            pair_rows[:, :, 0], pair_valid[:, :, 0], z, z, z,
+                            jnp.zeros((1, 4), jnp.int32),
+                            jnp.zeros((1, 4), jnp.int32))
+        ring = broker.RetryRing(ring_cw, ring_cw, ring_cw, ring_c, ring_cw,
+                                ring_c)
+        return broker.deliver_all(res, jnp.zeros((1, 0), jnp.int32), 8,
+                                  16384, max_notify, 8192,
+                                  target_brokers=brokers, num_brokers=4,
+                                  ring=ring, epochs=ring_c)
+
+    grid = (1, rows, users)
+    text = compile_for_tpu(deliver, (grid, jnp.int32), (grid, jnp.int32),
+                           (grid, jnp.bool_), ((1, users), jnp.int32),
+                           ((1, window), jnp.int32), ((1,), jnp.int32))
+    loops = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines() if re.search(r"\bwhile\(", line)]
+    assert not [name for name in loops if "/bad.send/" in name], loops
+    assert [name for name in loops if "/bad.ring/" in name], loops
